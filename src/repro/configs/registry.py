@@ -55,5 +55,11 @@ def get_smoke_config(name: str) -> ModelConfig:
     return reduced(mod.CONFIG)
 
 
+def describe(cfg: ModelConfig) -> str:
+    """The dimensions of ``cfg`` on one line (launcher banners)."""
+    return (f"{cfg.n_layers}L d_model={cfg.d_model} heads={cfg.n_heads}/"
+            f"{cfg.n_kv_heads}kv d_ff={cfg.d_ff} vocab={cfg.vocab_size}")
+
+
 def all_configs() -> Dict[str, ModelConfig]:
     return {a: get_config(a) for a in ARCH_IDS}

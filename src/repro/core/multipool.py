@@ -49,7 +49,7 @@ Two implementations of the same fold live here:
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -150,77 +150,116 @@ def combine_many(tables: Sequence[np.ndarray]
 
 # ---------------------------------------------------------------------------
 # jax twin of the fold - shared by the fused LUT pipeline's ref backend
-# (under jit) and its Pallas kernel body (the same jnp/lax primitives
-# lower in Mosaic). Lazy jax import keeps the numpy path numpy-only.
+# (under jit) and its Pallas kernel body. Every body is written against
+# primitives Mosaic lowers: a column at a traced index is a one-hot
+# reduction (exact: x + 0 and min(x, inf) change no bit), a lane shift
+# is ``pltpu.roll`` (jnp.roll under plain jit), and values stay 2-D,
+# columns as (R, 1). Tables may carry lane padding past column K (the
+# kernel pads to a lane multiple); no result at k <= K reads a padded
+# column. Lazy jax import keeps the numpy path numpy-only.
 # ---------------------------------------------------------------------------
 
 
-def minplus_fold_jnp(a, e):
+def col_jnp(x, i):
+    """Column ``i`` of the float (R, W) table ``x`` as (R, 1), without a
+    dynamic slice (the lane index ``i`` may be traced)."""
+    import jax
+    import jax.numpy as jnp
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.min(jnp.where(lane == i, x, float("inf")), axis=1,
+                   keepdims=True)
+
+
+def minplus_fold_jnp(a, e, K: Optional[int] = None):
     """jax :func:`minplus_fold`: same candidates, same order, same bits.
 
     Iterates the prefix count ``i`` ascending with a strict ``<`` update
     exactly like the numpy loop, so on equal inputs the returned values
     are bit-identical and the argmin trace picks the same (first)
-    minimum. ``e`` is shifted by the traced ``i`` through an inf-padded
-    ``dynamic_slice`` (no gathers), so this body lowers both under
-    ``jax.jit`` and inside a Pallas TPU kernel.
+    minimum. ``e`` is shifted by the traced ``i`` with a lane rotation
+    whose wrapped-around columns ``k < i`` are masked to +inf.
 
-    Returns ``(out, arg)`` with ``arg`` int32 (the numpy twin returns
-    int64; both hold prefix counts ``<= K``).
+    ``a``/``e`` are (R, W) with ``W >= K + 1`` (``K`` defaults to
+    ``W - 1``); columns past ``K`` are lane padding, never read for a
+    column ``<= K`` of the result. Returns ``(out, arg)`` (R, W) with
+    ``arg`` int32 (the numpy twin returns int64; both hold prefix counts
+    ``<= K``).
     """
     import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
 
-    R, K1 = a.shape
-    e_pad = jnp.concatenate(
-        [jnp.full((R, K1), float("inf"), a.dtype), e], axis=1)
+    R, W = a.shape
+    K = W - 1 if K is None else K
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
 
     def body(i, carry):
         out, arg = carry
-        f_col = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)
-        # g_shift[r, k] = e[r, k - i] for k >= i, else inf (the pad)
-        g_shift = jax.lax.dynamic_slice_in_dim(e_pad, K1 - i, K1, axis=1)
-        cand = f_col + g_shift
+        # g_shift[r, k] = e[r, k - i] for k >= i, else inf
+        g_shift = jnp.where(lane >= i, pltpu.roll(e, i, 1), float("inf"))
+        cand = col_jnp(a, i) + g_shift
         take = cand < out                  # strict: first minimum wins
         return (jnp.where(take, cand, out),
-                jnp.where(take, jnp.int32(i), arg))
+                jnp.where(take, i, arg))
 
-    out0 = jnp.full((R, K1), float("inf"), a.dtype)
-    arg0 = jnp.zeros((R, K1), jnp.int32)
-    return jax.lax.fori_loop(0, K1, body, (out0, arg0))
+    out0 = jnp.full((R, W), float("inf"), a.dtype)
+    arg0 = jnp.zeros((R, W), jnp.int32)
+    return jax.lax.fori_loop(0, K + 1, body, (out0, arg0))
 
 
-def backtrace_splits_jnp(args, i_opt, feasible, K: int, C: int):
-    """Vectorized split recovery from fold argmin traces (jax).
+def final_combine_jnp(F, E, K: int):
+    """The fold's last step, evaluated at ``k = K`` only:
+    ``min_i F[r, i] + E[r, K - i]`` with its first-minimum ``i``.
 
-    Args:
-      args: list of ``C - 2`` (R, K+1) int32 argmin traces (the middle
-        folds), possibly empty.
-      i_opt: (R,) int32 - argmin prefix count of the final combine.
-      feasible: (R,) bool.
-
-    Returns (R, C) int32 per-cluster counts; ``-1`` on infeasible rows.
-    The gather ``args[c][r, k[r]]`` is a one-hot reduction (no gather
-    op), so this helper also lowers inside the Pallas kernel body.
+    Returns ``(min_e, i_opt)``, both (R, 1). Scans ``i`` ascending with
+    a strict ``<`` update, so ``min_e`` holds the same bits and ``i_opt``
+    the same index as ``min``/``argmin`` over the reversed-table sum of
+    the numpy fold (an all-inf row keeps ``i_opt = 0``, as argmin does).
     """
     import jax
     import jax.numpy as jnp
 
-    R = i_opt.shape[0]
-    cols = []
-    k = i_opt.astype(jnp.int32)
-    last = K - k
+    R = F.shape[0]
+
+    def body(i, carry):
+        best, arg = carry
+        cand = col_jnp(F, i) + col_jnp(E, K - i)
+        take = cand < best
+        return jnp.where(take, cand, best), jnp.where(take, i, arg)
+
+    return jax.lax.fori_loop(
+        0, K + 1, body, (jnp.full((R, 1), float("inf"), F.dtype),
+                         jnp.zeros((R, 1), jnp.int32)))
+
+
+def backtrace_splits_jnp(args, i_opt, feasible, K: int, C: int):
+    """Split recovery from fold argmin traces (jax).
+
+    Args:
+      args: list of ``C - 2`` (R, W) int32 argmin traces (the middle
+        folds), possibly empty.
+      i_opt: (R, 1) int32 - argmin prefix count of the final combine.
+      feasible: (R, 1) bool.
+
+    Returns a list of ``C`` (R, 1) int32 per-cluster counts; ``-1`` on
+    infeasible rows. The lookup ``args[c][r, k[r]]`` is a one-hot
+    reduction (no gather op).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    k = i_opt
+    cols = {C - 1: K - k}
     for c in range(C - 2, 0, -1):
         a_c = args[c - 1]
-        iota = jax.lax.broadcasted_iota(jnp.int32, a_c.shape, 1)
-        i_prev = jnp.sum(jnp.where(iota == k[:, None], a_c, 0), axis=1)
-        cols.append((c, k - i_prev))
-        k = i_prev.astype(jnp.int32)
-    by_cluster = {0: k, C - 1: last}
-    by_cluster.update({c: v for c, v in cols})
-    splits = jnp.stack([by_cluster[c] for c in range(C)], axis=1)
-    return jnp.where(feasible[:, None], splits,
-                     jnp.full((R, C), -1, jnp.int32))
+        lane = jax.lax.broadcasted_iota(jnp.int32, a_c.shape, 1)
+        i_prev = jnp.sum(jnp.where(lane == k, a_c, 0), axis=1,
+                         keepdims=True)
+        cols[c] = k - i_prev
+        k = i_prev
+    cols[0] = k
+    return [jnp.where(feasible, cols[c], -1) for c in range(C)]
 
 
 def combine_rows_jnp(tables):
@@ -230,8 +269,8 @@ def combine_rows_jnp(tables):
     as the numpy fold, so the returned ``min_e`` bits and integer
     ``splits`` match :func:`combine_many` exactly on equal float32
     inputs. This is the combine the fused LUT pipeline's ref backend
-    jits; the Pallas kernel runs the same :func:`minplus_fold_jnp` /
-    :func:`backtrace_splits_jnp` bodies in-kernel.
+    jits; the Pallas kernel calls the same :func:`minplus_fold_jnp` /
+    :func:`final_combine_jnp` / :func:`backtrace_splits_jnp` in-kernel.
     """
     import jax.numpy as jnp
 
@@ -250,9 +289,6 @@ def combine_rows_jnp(tables):
         F, A = minplus_fold_jnp(F, tables[c])
         args.append(A)
 
-    cand = F + tables[C - 1][:, ::-1]      # cand[r, i] = F[r,i] + E[r,K-i]
-    i_opt = jnp.argmin(cand, axis=1).astype(jnp.int32)
-    min_e = jnp.min(cand, axis=1)
-    feasible = jnp.isfinite(min_e)
-    splits = backtrace_splits_jnp(args, i_opt, feasible, K, C)
-    return min_e, splits
+    min_e, i_opt = final_combine_jnp(F, tables[C - 1], K)
+    splits = backtrace_splits_jnp(args, i_opt, jnp.isfinite(min_e), K, C)
+    return min_e[:, 0], jnp.concatenate(splits, axis=1)

@@ -39,10 +39,7 @@ _ref_fold = jax.jit(dp_space_update_ref)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 BACKENDS = ("ref", "pallas", "pallas_interpret")

@@ -39,10 +39,7 @@ BACKENDS = ("ref", "pallas", "pallas_interpret")
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve_backend(backend: str = "auto") -> str:
@@ -60,7 +57,7 @@ def resolve_backend(backend: str = "auto") -> str:
 
 
 def lut_build(t_items, e_items, T: int, K: int, rows, *,
-              backend: str = "auto", bk: int = 512):
+              backend: str = "auto", bk: int = 128):
     """Fused Algorithm-1 + Algorithm-2 evaluation, batched over variants.
 
     Args:

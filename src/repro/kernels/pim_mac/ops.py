@@ -26,10 +26,7 @@ def _pad_to(x: jnp.ndarray, mult0: int, mult1: int) -> jnp.ndarray:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def pim_matmul(x_i8: jnp.ndarray, w_i8: jnp.ndarray,

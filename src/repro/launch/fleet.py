@@ -42,9 +42,10 @@ when the running deadline-miss rate crosses ``--miss-threshold``).
 
 With ``--decode`` (default on the flat path) every worker carries a real
 ``HeteroServeEngine``: each slice's placement is applied as an actual
-weight re-tiering and tokens are decoded through the tiered model on CPU.
+weight re-tiering and tokens are decoded through the tiered model.
 ``--no-decode`` runs the analytic scheduler/energy path only (fast; what
-``benchmarks/fleet_bench.py`` sweeps).
+``benchmarks/fleet_bench.py`` sweeps). ``--full-config`` takes the
+published config of ``--arch`` in place of the reduced smoke config.
 """
 from __future__ import annotations
 
@@ -58,6 +59,7 @@ from repro.fleet.forecast import FORECASTERS
 from repro.fleet.hierarchy import CELL_POLICIES
 from repro.fleet.router import POLICIES
 from repro.fleet.traces import TRACES
+from repro.launch import compile_cache
 
 
 def _dag_tenants(spec_str):
@@ -154,6 +156,9 @@ def main(argv=None) -> None:
                          "in the dvfs-controller: summary")
     ap.add_argument("--tokens-per-task", type=int, default=2)
     ap.add_argument("--arch", default="internlm2_1_8b")
+    ap.add_argument("--full-config", action="store_true",
+                    help="model the published config of --arch instead of "
+                         "the reduced smoke config")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--decode", dest="decode", action="store_true",
                     default=True)
@@ -224,14 +229,18 @@ def main(argv=None) -> None:
         args.decode = False
 
     params = cfg = None
+    if args.decode or args.full_config:
+        from repro.configs import (canonical, describe, get_config,
+                                   get_smoke_config)
+        cfg = (get_config if args.full_config
+               else get_smoke_config)(args.arch)
+        print(f"arch={canonical(args.arch)} ({describe(cfg)}, "
+              f"{'published' if args.full_config else 'reduced'} config)")
     if args.decode:
         import jax
-        from repro.configs import canonical, get_smoke_config
         from repro.models import lm
-        cfg = get_smoke_config(args.arch)
+        compile_cache.enable()
         params = lm.init_lm(jax.random.PRNGKey(args.seed), cfg)
-        print(f"arch={canonical(args.arch)} ({cfg.n_layers}L "
-              f"d={cfg.d_model}, reduced config)")
 
     pc = api.compiler()
     if args.lut_cache:

@@ -12,8 +12,9 @@ unrolled reduced configs (tests/test_roofline.py), while collective bytes
 come from the compiled HLO with while-trip multipliers
 (repro.launch.hloparse).
 
-Hardware constants (TPU v5e class): 197 TFLOP/s bf16, 819 GB/s HBM,
-50 GB/s/link ICI.
+Hardware constants are those of one TPU v5e chip and hold for v5e only:
+197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link ICI. Nothing here checks the
+device kind, so a roofline of any other device is wrong, not approximate.
 """
 from __future__ import annotations
 
@@ -27,6 +28,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.launch.specs import SHAPES, cell_is_applicable, dryrun_config
 from repro.models.common import ModelConfig
 
+# TPU v5e only (per chip); other device kinds need their own peaks
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9

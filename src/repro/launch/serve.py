@@ -2,12 +2,16 @@
 
 Two modes:
   * ``--engine batch``  - plain batched decode engine (slot continuous
-    batching) on the reduced config.
+    batching).
   * ``--engine hetero`` - the HH-PIM heterogeneous runtime: requests flow
     through time slices, weight placement re-solved per slice across
     {hp,lp} x {bf16,int8} tiers (the paper's technique, TPU constants).
     Built through the ``repro.api`` facade; ``--substrate`` / ``--solver``
     pick registry entries (DESIGN.md SS.5).
+
+Both run the reduced (smoke) config of ``--arch`` unless
+``--full-config`` selects the published one; the banner prints the
+dimensions that ran. Seeded random weights either way.
 """
 from __future__ import annotations
 
@@ -16,8 +20,10 @@ import argparse
 import jax
 
 from repro import api
-from repro.configs import ARCH_IDS, canonical, get_smoke_config
+from repro.configs import (ARCH_IDS, canonical, describe, get_config,
+                           get_smoke_config)
 from repro.core import workloads
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve.engine import DecodeEngine, Request
 
@@ -35,12 +41,17 @@ def main() -> None:
                     help=f"one of {api.available_substrates()}")
     ap.add_argument("--solver", default=None,
                     help=f"placement solver, one of {sorted(api.SOLVERS)}")
+    ap.add_argument("--full-config", action="store_true",
+                    help="run the published config instead of the "
+                         "reduced smoke config")
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch)
+    compile_cache.enable()
+    cfg = (get_config if args.full_config else get_smoke_config)(args.arch)
     params = lm.init_lm(jax.random.PRNGKey(0), cfg)
-    print(f"arch={canonical(args.arch)} ({cfg.n_layers}L d={cfg.d_model}, "
-          f"reduced config) engine={args.engine}")
+    print(f"arch={canonical(args.arch)} ({describe(cfg)}, "
+          f"{'published' if args.full_config else 'reduced'} config) "
+          f"engine={args.engine}")
 
     if args.engine == "batch":
         eng = DecodeEngine(cfg, params, max_batch=4, max_len=64)

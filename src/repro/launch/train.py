@@ -14,6 +14,7 @@ import signal
 
 from repro.configs import ARCH_IDS, canonical, get_config, get_smoke_config
 from repro.data.synthetic import DataConfig
+from repro.launch import compile_cache
 from repro.launch.specs import dryrun_config
 from repro.optim.adamw import OptimizerConfig
 from repro.train.step import default_optimizer_kind
@@ -33,6 +34,7 @@ def main() -> None:
                     help="use the full assigned config (requires a pod)")
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = (dryrun_config(get_config(args.arch))
            if args.full else get_smoke_config(args.arch))
     print(f"arch={canonical(args.arch)} layers={cfg.n_layers} "

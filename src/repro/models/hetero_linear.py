@@ -59,9 +59,11 @@ def split_weight(w: jnp.ndarray, counts: Dict[str, int],
 
 
 def tiered_matmul(x: jnp.ndarray, segs: Dict[str, dict],
-                  backend: str = "ref") -> jnp.ndarray:
+                  backend: str = "auto") -> jnp.ndarray:
     """x: (..., d_in) -> (..., d_out), concatenating tier outputs in
-    the segments' split order (the dict's insertion order)."""
+    the segments' split order (the dict's insertion order). ``backend``
+    is the ``pim_matmul`` backend of the int8 tiers ("auto": the Pallas
+    kernel on TPU, the jnp reference elsewhere)."""
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     outs = []

@@ -73,10 +73,14 @@ class DecodeEngine:
         # per-slot absolute decode position (requests start at different
         # times; attention_decode takes a position vector)
         self._slot_pos = np.zeros(max_batch, np.int32)
+        # params enter every jitted call as an argument: a closed-over
+        # array would be embedded in the program as a constant
         self._step_fn = jax.jit(
-            lambda st, tk, pos: lm.decode_step(params, cfg, st, tk, pos))
+            lambda p, st, tk, pos: lm.decode_step(p, cfg, st, tk, pos))
         self._prefill_fns: Dict[Tuple[int, int], callable] = {}
         self.step_times_s: List[float] = []
+        # (max_batch, vocab) logits of the last decode step, on device
+        self.last_logits = None
 
     def submit(self, req: Request) -> None:
         req.t_submit = time.perf_counter()
@@ -94,9 +98,9 @@ class DecodeEngine:
         stays O(log max_batch * distinct prompt lengths)."""
         key = (n, L)
         if key not in self._prefill_fns:
-            cfg, params, max_len = self.cfg, self.params, self.max_len
+            cfg, max_len = self.cfg, self.max_len
 
-            def fn(prompts):              # (n, L) int32
+            def fn(params, prompts):      # prompts: (n, L) int32
                 state = lm.init_decode_state(cfg, n, max_len)
 
                 def body(carry, tok):
@@ -157,7 +161,8 @@ class DecodeEngine:
                 mat[j] = r.prompt
             with obs.span("engine.prefill", "engine", n_requests=len(group),
                           bucket=n, prompt_len=L):
-                new_state = self._prefill_fn(n, L)(jnp.asarray(mat))
+                new_state = self._prefill_fn(n, L)(self.params,
+                                                   jnp.asarray(mat))
                 self._scatter_state([i for i, _ in group], new_state)
             for i, r in group:
                 toks[i] = r.prompt[-1]
@@ -174,8 +179,13 @@ class DecodeEngine:
             return {}
         _obs = obs.enabled()
         _t0 = obs.now_ns() if _obs else 0
-        logits, self._state = self._step_fn(self._state, self._toks,
-                                            jnp.asarray(self._slot_pos))
+        # jnp.array copies: the CPU client may alias a host array that
+        # jnp.asarray wraps, and _slot_pos is bumped before the
+        # asynchronously dispatched step has read it
+        logits, self._state = self._step_fn(self.params, self._state,
+                                            self._toks,
+                                            jnp.array(self._slot_pos))
+        self.last_logits = logits
         if _obs:
             obs.complete("engine.decode_step", _t0, cat="engine", args={
                 "active": sum(s is not None and not s.done

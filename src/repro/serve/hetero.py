@@ -112,6 +112,28 @@ def tpu_model_spec(cfg: ModelConfig, tokens_per_task: int) -> sp.ModelSpec:
     return sp.ModelSpec(f"{cfg.name}_serve", n_params, macs, 1.0)
 
 
+def _ffn_weights(stack):
+    """``((layer, name), w)`` for every 2-D FFN ``w_up``/``w_gate``
+    matrix of a decoder stack, in layer order. A scanned group
+    (``stack["scan"]``, leading layer axis) yields one matrix per layer,
+    keyed ``scan.<group>.<period slot>``."""
+    def mats(ffn, ndim):
+        return {n: ffn[n] for n in ("w_up", "w_gate")
+                if n in ffn and ffn[n].ndim == ndim}
+
+    for lname, layer in stack.items():
+        if lname != "scan":
+            for wname, w in mats(layer.get("ffn") or {}, 2).items():
+                yield (lname, wname), w
+            continue
+        for pname, blk in layer.items():
+            ws = mats(blk.get("ffn") or {}, 3)
+            n_groups = next(iter(ws.values())).shape[0] if ws else 0
+            for g in range(n_groups):
+                for wname, w in ws.items():
+                    yield (f"scan.{g}.{pname}", wname), w[g]
+
+
 @dataclasses.dataclass
 class HeteroSliceResult:
     report: SliceReport
@@ -143,8 +165,9 @@ class HeteroServeEngine:
                 tokens_per_task=tokens_per_task, rho=rho,
                 peak_tasks=peak_tasks)
         if cfg is None:
-            from repro.configs import get_smoke_config
-            cfg = get_smoke_config("internlm2_1_8b")
+            raise ValueError(
+                "HeteroServeEngine needs the ModelConfig its params were "
+                "built from (repro.configs.get_config or get_smoke_config)")
         self.cfg = cfg
         self.params = params
         self.substrate = substrate
@@ -184,22 +207,14 @@ class HeteroServeEngine:
         formats = {t: f for _, t, f in self._tier_plan}
         order = tuple(t for _, t, _ in self._tier_plan)
         tiers = {}
-        stack = self.params["stack"]
-        for lname, layer in stack.items():
-            ffn = layer.get("ffn") if isinstance(layer, dict) else None
-            if not ffn:
-                continue
-            for wname in ("w_up", "w_gate"):
-                if wname not in ffn:
-                    continue
-                w = ffn[wname]
-                counts = fractions_to_counts(
-                    w.shape[-1],
-                    {space_to_tier[k]: v for k, v in placement.items()},
-                    K, order=order)
-                tiers[(lname, wname)] = split_weight(
-                    jnp.asarray(w, jnp.float32),
-                    {t: counts.get(t, 0) for t in order}, formats=formats)
+        for key, w in _ffn_weights(self.params["stack"]):
+            counts = fractions_to_counts(
+                w.shape[-1],
+                {space_to_tier[k]: v for k, v in placement.items()},
+                K, order=order)
+            tiers[key] = split_weight(
+                jnp.asarray(w, jnp.float32),
+                {t: counts.get(t, 0) for t in order}, formats=formats)
         self._tiered = tiers
         self._tiered_placement = dict(placement)
         if _obs:
@@ -257,12 +272,13 @@ class HeteroServeEngine:
         self.history.append(res)
         return res
 
-    def tiered_forward(self, x: jnp.ndarray, layer: str = None):
-        """Run one tiered FFN matmul (placement-split) - used by tests to
-        check placement invariance of the math."""
+    def tiered_forward(self, x: jnp.ndarray, backend: str = "auto"):
+        """Run the first layer's tiered FFN up/gate matmul
+        (placement-split, int8 tiers through ``pim_matmul`` on
+        ``backend``) - used to check placement invariance of the math."""
         assert self._tiered, "run_slice first"
         key = next(iter(self._tiered))
-        return tiered_matmul(x, self._tiered[key])
+        return tiered_matmul(x, self._tiered[key], backend=backend)
 
     # -- summaries ----------------------------------------------------------
     def energy_uj(self) -> float:

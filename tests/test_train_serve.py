@@ -332,6 +332,55 @@ def test_hetero_engine_adapts_and_meets_deadlines():
     assert y.shape == (2, cfg.d_ff)
 
 
+def test_decode_step_reads_positions_it_was_given():
+    """The per-slot positions handed to the asynchronously dispatched
+    step must not alias the host array the engine bumps right after."""
+    cfg = _tiny_cfg()
+    eng = DecodeEngine(cfg, lm.init_lm(jax.random.PRNGKey(0), cfg),
+                       max_batch=2, max_len=16)
+    # the CPU client wraps a 64-byte-aligned host array without copying;
+    # align the positions so that an aliasing engine fails every time
+    buf = np.zeros(64, np.int32)
+    start = (-buf.ctypes.data % 64) // buf.itemsize
+    eng._slot_pos = buf[start:start + 2]
+    seen = []
+    step = eng._step_fn
+
+    def spy(p, st, tk, pos):
+        seen.append(pos)
+        return step(p, st, tk, pos)
+
+    eng._step_fn = spy
+    eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=2))
+    eng.step()
+    # the last prompt token decodes at position len(prompt) - 1
+    assert int(np.asarray(seen[0])[0]) == 2
+    assert int(eng._slot_pos[0]) == 3
+
+
+def test_hetero_engine_requires_its_config():
+    params = lm.init_lm(jax.random.PRNGKey(1), _tiny_cfg())
+    with pytest.raises(ValueError, match="ModelConfig"):
+        HeteroServeEngine(None, params, t_slice_ms=200.0)
+
+
+def test_hetero_engine_tiers_every_layer_of_a_scanned_stack():
+    """The published configs scan their layers (stacked weights with a
+    leading layer axis): each layer's up/gate matrix is tiered."""
+    import dataclasses
+    cfg = dataclasses.replace(_tiny_cfg(), n_layers=3, scan_layers=True)
+    params = lm.init_lm(jax.random.PRNGKey(1), cfg)
+    assert "scan" in params["stack"]
+    eng = HeteroServeEngine(cfg, params, t_slice_ms=200.0, max_batch=4)
+    eng.run_slice(2)
+    assert sorted(eng._tiered) == sorted(
+        (f"scan.{g}.p0", w) for g in range(3) for w in ("w_up", "w_gate"))
+    x = jnp.ones((2, cfg.d_model), jnp.float32)
+    y = eng.tiered_forward(x)
+    dense = x @ params["stack"]["scan"]["p0"]["ffn"]["w_up"][0]
+    assert float(jnp.abs(y - dense).max() / jnp.abs(dense).max()) < 0.08
+
+
 def test_tpu_arch_spaces_sane():
     arch = tpu_arch(4, 4)
     names = {s.name for s in arch.spaces}
