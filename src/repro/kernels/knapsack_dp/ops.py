@@ -69,8 +69,6 @@ def knapsack_dp(t_items: Sequence[int], e_items: Sequence[float],
       stacked to (n+1, T+1, K+1), for backtracing placements.
     """
     backend = resolve_backend(backend)
-    _obs = obs.enabled()
-    _t0 = obs.now_ns() if _obs else 0
     dp = jnp.full((T + 1, K + 1), jnp.inf, dtype=jnp.float32)
     dp = dp.at[:, 0].set(0.0)
     stages = [dp]
@@ -85,12 +83,10 @@ def knapsack_dp(t_items: Sequence[int], e_items: Sequence[float],
                 interpret=(backend == "pallas_interpret"))
         if return_stages:
             stages.append(dp)
-    if _obs:
+    if obs.enabled():
         # dispatch accounting keyed by the RESOLVED backend, so a trace
         # shows whether the kernel, interpreter or ref path actually ran
         obs.counter("kernels.knapsack_dp.dispatch", backend=backend)
-        obs.observe("kernels.knapsack_dp.us",
-                    (obs.now_ns() - _t0) / 1e3, backend=backend)
     if return_stages:
         return jnp.stack(stages)
     return dp

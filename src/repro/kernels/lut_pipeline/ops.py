@@ -93,8 +93,6 @@ def lut_build(t_items, e_items, T: int, K: int, rows, *,
     r = jnp.asarray(rows, jnp.int32)
     if r.ndim == 1:
         r = jnp.broadcast_to(r[None, :], (V, r.shape[0]))
-    _obs = obs.enabled()
-    _t0 = obs.now_ns() if _obs else 0
     if backend == "ref":
         stages, min_e, splits = lut_pipeline_ref(t, e, r, T=T, K=K)
     else:
@@ -104,10 +102,8 @@ def lut_build(t_items, e_items, T: int, K: int, rows, *,
     base = jnp.full((V, t.shape[1], 1, T + 1, K + 1), jnp.inf, jnp.float32)
     base = base.at[..., 0].set(0.0)
     stages = jnp.concatenate([base, stages], axis=2)
-    if _obs:
+    if obs.enabled():
         # dispatch accounting keyed by the RESOLVED backend, so a trace
         # shows whether the kernel, interpreter or ref path actually ran
         obs.counter("kernels.lut_pipeline.dispatch", backend=backend)
-        obs.observe("kernels.lut_pipeline.us",
-                    (obs.now_ns() - _t0) / 1e3, backend=backend)
     return stages, min_e, splits

@@ -27,6 +27,12 @@ Rare events (a compiler LUT build, an autoscaler scale event) may write
 through :func:`metrics` unconditionally; that is what keeps the fleet
 CLI's lut-cache/autoscale reporting truthful even with tracing off.
 
+The device path (the decode engine) uses :func:`profiled_span` in
+place of :func:`span`: it always writes a ``jax.profiler`` host span,
+which costs about a microsecond with no profile being captured and
+lands on the profiler's clock beside the device's own events when one
+is, and also records into the tracer while tracing is on.
+
 Enable with :func:`enable` (optionally attaching a
 :class:`~repro.obs.flight.FlightRecorder`), read back through
 ``repro.api.obs()``, export with :func:`export`. The state is
@@ -41,13 +47,15 @@ from repro.obs.flight import FlightRecorder  # noqa: F401
 from repro.obs.metrics import (TIME_US_BUCKETS,  # noqa: F401
                                WAIT_SLICE_BUCKETS, Histogram,
                                MetricsRegistry)
-from repro.obs.trace import (NULL_SPAN, NullSpan, Span,  # noqa: F401
-                             Tracer, now_ns, summarize_events)
+from repro.obs.trace import (NULL_SPAN, NullSpan,  # noqa: F401
+                             ProfiledSpan, Span, Tracer, now_ns,
+                             summarize_events)
 
 __all__ = [
     "enabled", "enable", "disable", "reset",
     "tracer", "metrics", "flight_recorder", "set_flight_recorder",
-    "span", "instant", "complete", "counter", "gauge", "observe",
+    "span", "profiled_span", "instant", "complete", "counter", "gauge",
+    "observe",
     "export", "now_ns", "summarize_events",
     "Tracer", "MetricsRegistry", "FlightRecorder", "Histogram",
     "NULL_SPAN",
@@ -113,6 +121,20 @@ def span(name: str, cat: str = "repro", *, tid: Optional[int] = None,
     if not _enabled:
         return NULL_SPAN
     return _tracer.span(name, cat, tid=tid, **attrs)
+
+
+def profiled_span(name: str, cat: str = "engine", **attrs):
+    """Context-manager span on the profiler's clock: always a
+    ``jax.profiler.TraceAnnotation`` (visible in a profile capture, where
+    the trace reducer aligns it with device time), plus the same span in
+    the tracer while tracing is on. Attrs are fixed at entry; keep them
+    small ints or short strings without commas (the profiler splits its
+    metadata on commas)."""
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name, **attrs)
+    if not _enabled:
+        return ann
+    return ProfiledSpan(ann, _tracer.span(name, cat, **attrs))
 
 
 def complete(name: str, t_start_ns: int, *, cat: str = "repro",

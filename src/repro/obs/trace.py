@@ -86,6 +86,26 @@ class NullSpan:
 NULL_SPAN = NullSpan()
 
 
+class ProfiledSpan:
+    """A profiler annotation and a tracer span entered and left
+    together (``repro.obs.profiled_span`` while tracing is on)."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, span: Span):
+        self._ann = ann
+        self._span = span
+
+    def __enter__(self) -> "ProfiledSpan":
+        self._ann.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+
+
 class Tracer:
     """Thread-safe collector of Chrome trace events (ts/dur in us)."""
 

@@ -74,11 +74,15 @@ class DecodeEngine:
         # times; attention_decode takes a position vector)
         self._slot_pos = np.zeros(max_batch, np.int32)
         # params enter every jitted call as an argument: a closed-over
-        # array would be embedded in the program as a constant
-        self._step_fn = jax.jit(
-            lambda p, st, tk, pos: lm.decode_step(p, cfg, st, tk, pos))
+        # array would be embedded in the program as a constant. The
+        # function's name names the compiled module (jit_decode_step),
+        # which is how a profile finds the step.
+
+        def decode_step(params, state, tokens, pos):
+            return lm.decode_step(params, cfg, state, tokens, pos)
+
+        self._step_fn = jax.jit(decode_step)
         self._prefill_fns: Dict[Tuple[int, int], callable] = {}
-        self.step_times_s: List[float] = []
         # (max_batch, vocab) logits of the last decode step, on device
         self.last_logits = None
 
@@ -100,7 +104,7 @@ class DecodeEngine:
         if key not in self._prefill_fns:
             cfg, max_len = self.cfg, self.max_len
 
-            def fn(params, prompts):      # prompts: (n, L) int32
+            def prefill(params, prompts):  # prompts: (n, L) int32
                 state = lm.init_decode_state(cfg, n, max_len)
 
                 def body(carry, tok):
@@ -113,7 +117,7 @@ class DecodeEngine:
                     jnp.swapaxes(prompts, 0, 1)[:-1])
                 return state
 
-            self._prefill_fns[key] = jax.jit(fn)
+            self._prefill_fns[key] = jax.jit(prefill)   # jit_prefill
         return self._prefill_fns[key]
 
     def _scatter_state(self, slot_idx: List[int], new_state) -> None:
@@ -136,81 +140,91 @@ class DecodeEngine:
         self._state = dict(self._state)
         self._state["layers"] = layers
 
-    def _fill_slots(self) -> None:
-        refills: List[Tuple[int, Request]] = []
-        for i, s in enumerate(self.slots):
-            if (s is None or s.done) and self.queue:
-                req = self.queue.pop(0)
+    def _seating(self) -> Tuple[List[int], List[Request]]:
+        """The free slots and the queue's head that will fill them."""
+        free = [i for i, s in enumerate(self.slots) if s is None or s.done]
+        return free, self.queue[:len(free)]
+
+    def _fill_slots(self, seating=None) -> None:
+        free, seat = self._seating() if seating is None else seating
+        if not seat:
+            return
+        with obs.profiled_span("engine.refill", seated=len(seat)):
+            del self.queue[:len(seat)]
+            for i, req in zip(free, seat):
                 req.t_start = time.perf_counter()
                 self.slots[i] = req
-                refills.append((i, req))
-        if not refills:
-            return
-        # one batched prefill per distinct prompt length: no pad tokens
-        # ever reach the state, so recurrent layers and local-attention
-        # ring buffers see exactly the prompt prefix (padding could only
-        # be masked out of full-attention KV, not of carried state)
-        by_len: Dict[int, List[Tuple[int, Request]]] = {}
-        for i, r in refills:
-            by_len.setdefault(len(r.prompt), []).append((i, r))
-        toks = np.array(self._toks)
-        for L, group in by_len.items():
-            n = self._bucket(len(group))
-            mat = np.zeros((n, L), np.int32)
-            for j, (_, r) in enumerate(group):
-                mat[j] = r.prompt
-            with obs.span("engine.prefill", "engine", n_requests=len(group),
-                          bucket=n, prompt_len=L):
-                new_state = self._prefill_fn(n, L)(self.params,
-                                                   jnp.asarray(mat))
+            # one batched prefill per distinct prompt length: no pad
+            # tokens ever reach the state, so recurrent layers and
+            # local-attention ring buffers see exactly the prompt prefix
+            # (padding could only be masked out of full-attention KV, not
+            # of carried state)
+            by_len: Dict[int, List[Tuple[int, Request]]] = {}
+            for i, r in zip(free, seat):
+                by_len.setdefault(len(r.prompt), []).append((i, r))
+            toks = np.array(self._toks)
+            for L, group in by_len.items():
+                n = self._bucket(len(group))
+                mat = np.zeros((n, L), np.int32)
+                for j, (_, r) in enumerate(group):
+                    mat[j] = r.prompt
+                with obs.profiled_span("engine.prefill", bucket=n,
+                                       prompt_len=L):
+                    new_state = self._prefill_fn(n, L)(self.params,
+                                                       jnp.asarray(mat))
                 self._scatter_state([i for i, _ in group], new_state)
-            for i, r in group:
-                toks[i] = r.prompt[-1]
-                # prompt prefix state covers positions 0..L-2; the last
-                # prompt token is decoded next step at its position L-1
-                self._slot_pos[i] = L - 1
-        self._toks = jnp.asarray(toks)
+                for i, r in group:
+                    toks[i] = r.prompt[-1]
+                    # prompt prefix state covers positions 0..L-2; the
+                    # last prompt token is decoded next step at its
+                    # position L-1
+                    self._slot_pos[i] = L - 1
+            self._toks = jnp.asarray(toks)
 
     def step(self) -> Dict[int, int]:
-        """Decode one token for every active slot; returns {rid: token}."""
-        t0 = time.perf_counter()
-        self._fill_slots()
-        if all(s is None or s.done for s in self.slots):
+        """Decode one token for every active slot; returns {rid: token}.
+
+        Profiled spans (``engine.*``, DESIGN.md §8) split the step into
+        disjoint phases under ``engine.step``: ``engine.refill`` (seating,
+        prefill, state scatter), ``engine.dispatch`` (the step's launch),
+        ``engine.readback`` (the host waits for the step's argmax) and
+        ``engine.bookkeep`` (per-slot tokens and completion)."""
+        free, seat = seating = self._seating()
+        active = self.max_batch - len(free) + len(seat)
+        if not active:
             return {}
-        _obs = obs.enabled()
-        _t0 = obs.now_ns() if _obs else 0
-        # jnp.array copies: the CPU client may alias a host array that
-        # jnp.asarray wraps, and _slot_pos is bumped before the
-        # asynchronously dispatched step has read it
-        logits, self._state = self._step_fn(self.params, self._state,
-                                            self._toks,
-                                            jnp.array(self._slot_pos))
-        self.last_logits = logits
-        if _obs:
-            obs.complete("engine.decode_step", _t0, cat="engine", args={
-                "active": sum(s is not None and not s.done
-                              for s in self.slots),
-                "max_batch": self.max_batch})
-        self._slot_pos += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        out = {}
-        toks = np.asarray(self._toks).copy()
-        now = time.perf_counter()
-        for i, req in enumerate(self.slots):
-            if req is None or req.done:
-                continue
-            tok = int(nxt[i])
-            req.out.append(tok)
-            if req.t_first_token is None:
-                req.t_first_token = now
-            out[req.rid] = tok
-            toks[i] = tok
-            if len(req.out) >= req.max_new_tokens:
-                req.done = True
-                req.t_done = now
-                self.completed.append(req)
-        self._toks = jnp.asarray(toks)
-        self.step_times_s.append(time.perf_counter() - t0)
+        with obs.profiled_span("engine.step", active=active,
+                               seated=len(seat)):
+            self._fill_slots(seating)
+            with obs.profiled_span("engine.dispatch"):
+                # jnp.array copies: the CPU client may alias a host array
+                # that jnp.asarray wraps, and _slot_pos is bumped before
+                # the asynchronously dispatched step has read it
+                logits, self._state = self._step_fn(
+                    self.params, self._state, self._toks,
+                    jnp.array(self._slot_pos))
+            self.last_logits = logits
+            self._slot_pos += 1
+            with obs.profiled_span("engine.readback"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with obs.profiled_span("engine.bookkeep"):
+                out = {}
+                toks = np.asarray(self._toks).copy()
+                now = time.perf_counter()
+                for i, req in enumerate(self.slots):
+                    if req is None or req.done:
+                        continue
+                    tok = int(nxt[i])
+                    req.out.append(tok)
+                    if req.t_first_token is None:
+                        req.t_first_token = now
+                    out[req.rid] = tok
+                    toks[i] = tok
+                    if len(req.out) >= req.max_new_tokens:
+                        req.done = True
+                        req.t_done = now
+                        self.completed.append(req)
+                self._toks = jnp.asarray(toks)
         return out
 
     def drain_completed(self) -> List[Request]:

@@ -200,28 +200,28 @@ class HeteroServeEngine:
     def _retier(self, placement: Dict[str, int]) -> bool:
         if placement == self._tiered_placement:
             return False
-        _obs = obs.enabled()
-        _t0 = obs.now_ns() if _obs else 0
         K = self.model_spec.n_params
         space_to_tier = {s: t for s, t, _ in self._tier_plan}
         formats = {t: f for _, t, f in self._tier_plan}
         order = tuple(t for _, t, _ in self._tier_plan)
+        weights = list(_ffn_weights(self.params["stack"]))
         tiers = {}
-        for key, w in _ffn_weights(self.params["stack"]):
-            counts = fractions_to_counts(
-                w.shape[-1],
-                {space_to_tier[k]: v for k, v in placement.items()},
-                K, order=order)
-            tiers[key] = split_weight(
-                jnp.asarray(w, jnp.float32),
-                {t: counts.get(t, 0) for t in order}, formats=formats)
+        # a migration = weights actually re-quantized and re-split
+        with obs.profiled_span(
+                "engine.migration", n_weights=len(weights),
+                placement=" ".join(f"{k}:{v}"
+                                   for k, v in sorted(placement.items()))):
+            for key, w in weights:
+                counts = fractions_to_counts(
+                    w.shape[-1],
+                    {space_to_tier[k]: v for k, v in placement.items()},
+                    K, order=order)
+                tiers[key] = split_weight(
+                    jnp.asarray(w, jnp.float32),
+                    {t: counts.get(t, 0) for t in order}, formats=formats)
         self._tiered = tiers
         self._tiered_placement = dict(placement)
-        if _obs:
-            # a migration = weights actually re-quantized and re-split
-            obs.complete("engine.migration", _t0, cat="engine",
-                         args={"placement": dict(placement),
-                               "n_weights": len(tiers)})
+        if obs.enabled():
             obs.counter("engine.migrations")
         return True
 
@@ -240,14 +240,10 @@ class HeteroServeEngine:
 
     def _decode_tokens(self, n_requests: int) -> np.ndarray:
         """Decode one token per active request through the tiered model."""
-        _obs = obs.enabled()
-        _t0 = obs.now_ns() if _obs else 0
-        logits, self._state = lm.decode_step(
-            self.params, self.cfg, self._state, self._toks,
-            jnp.int32(self._pos))
-        if _obs:
-            obs.complete("engine.decode", _t0, cat="engine",
-                         args={"n_requests": n_requests})
+        with obs.profiled_span("engine.decode", n_requests=n_requests):
+            logits, self._state = lm.decode_step(
+                self.params, self.cfg, self._state, self._toks,
+                jnp.int32(self._pos))
         # tiered verification path: run the first tiered FFN on the final
         # hidden state proxy to exercise placement-dependent compute
         self._pos += 1

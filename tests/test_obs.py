@@ -272,3 +272,45 @@ def test_api_obs_facade():
     from repro import api
 
     assert api.obs() is obs
+
+
+# -- profiled spans (device path) ---------------------------------------------
+
+
+def test_profiled_span_records_in_the_tracer_only_when_enabled():
+    with obs.profiled_span("engine.step", active=3, seated=1):
+        pass
+    assert len(obs.tracer()) == 0
+    obs.enable()
+    with obs.profiled_span("engine.step", active=3, seated=1):
+        with obs.profiled_span("engine.dispatch"):
+            pass
+    outer, inner = sorted(obs.tracer().events(), key=lambda e: e["ts"])
+    assert (outer["name"], outer["cat"], outer["args"]) == \
+        ("engine.step", "engine", {"active": 3, "seated": 1})
+    assert inner["name"] == "engine.dispatch" and inner["args"] == {}
+    assert outer["ts"] <= inner["ts"] and \
+        inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+def test_profiled_span_lands_on_the_profilers_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.profiled_span("engine.migration", n_weights=2,
+                               placement="hp:3 lp:1"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [dict(e.stats)
+             for plane in ProfileData.from_file(path[0]).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name == "engine.migration"]
+    assert found == [{"n_weights": 2, "placement": "hp:3 lp:1"}]
+    assert len(obs.tracer()) == 0        # tracing stayed off

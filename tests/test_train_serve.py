@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.configs import get_smoke_config
 from repro.data.synthetic import DataConfig
 from repro.models import lm
@@ -250,12 +251,23 @@ def test_request_latency_accounting():
     eng = DecodeEngine(cfg, params, max_batch=2, max_len=64)
     for r in range(3):
         eng.submit(Request(rid=r, prompt=[1, 2, 3], max_new_tokens=2))
-    done = eng.run_until_done()
+    obs.reset()
+    obs.enable()
+    try:
+        done = eng.run_until_done()
+        steps = [e for e in obs.tracer().events()
+                 if e["name"] == "engine.step"]
+    finally:
+        obs.reset()
     for r in done:
         assert r.t_submit is not None and r.t_done is not None
         assert r.t_submit <= r.t_start <= r.t_first_token <= r.t_done
         assert r.latency_s >= 0 and r.queue_wait_s >= 0
-    assert eng.step_times_s and all(t > 0 for t in eng.step_times_s)
+    # two slots, three requests of two tokens: seat two, finish them,
+    # seat the third, finish it
+    assert [(e["args"]["active"], e["args"]["seated"]) for e in steps] \
+        == [(2, 2), (2, 0), (1, 1), (1, 0)]
+    assert all(e["cat"] == "engine" and e["dur"] > 0 for e in steps)
 
 
 def test_tiered_matmul_matches_dense():
