@@ -430,6 +430,43 @@ def decode_step(params, cfg: ModelConfig, state, tokens, pos
     return logits, new_state
 
 
+# Leaves the blocks read in float32: the norm gains (``rms_norm`` casts its
+# ``scale`` to float32) and the recurrent decay and gate biases, which are
+# added to float32 pre-activations. Every other float leaf is read only
+# through ``.astype(cfg.dtype)`` (``x.dtype`` is ``cfg.dtype`` in a step).
+_FLOAT32_LEAVES = frozenset({"ln1", "ln2", "ln_cross", "final_ln",
+                             "enc_final_ln", "lam", "b_a", "b_x", "bf",
+                             "bi"})
+
+
+def compute_casts(params, cfg: ModelConfig) -> PyTree:
+    """A tree of bools like ``params``: True at each float leaf that the
+    model reads only as ``cfg.dtype`` and that is not in ``cfg.dtype``."""
+    def cast(path, x):
+        return (getattr(path[-1], "key", None) not in _FLOAT32_LEAVES
+                and jnp.issubdtype(x.dtype, jnp.floating)
+                and x.dtype != cfg.dtype)
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _astype_all(leaves, dtype):
+    return tuple(x.astype(dtype) for x in leaves)
+
+
+def compute_params(params, cfg: ModelConfig) -> PyTree:
+    """``params`` with the ``compute_casts`` leaves cast to ``cfg.dtype``
+    in one jitted call: ``decode_step`` then multiplies the operands it
+    would have cast itself, bit for bit, without casting them each call.
+    Every other leaf is the caller's own array; ``params`` is unchanged."""
+    flat, treedef = jax.tree_util.tree_flatten(params)
+    mask = jax.tree_util.tree_leaves(compute_casts(params, cfg))
+    cast = iter(_astype_all(tuple(x for m, x in zip(mask, flat) if m),
+                            jnp.dtype(cfg.dtype)) if any(mask) else ())
+    return jax.tree_util.tree_unflatten(
+        treedef, [next(cast) if m else x for m, x in zip(mask, flat)])
+
+
 def prefill(params, cfg: ModelConfig, tokens, *, max_len: int,
             prefix_embeds=None, enc_frames=None
             ) -> Tuple[jnp.ndarray, PyTree]:

@@ -20,6 +20,7 @@ bounding XLA compiles at O(log max_batch * distinct prompt lengths).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -58,11 +59,53 @@ class Request:
         return self.t_start - self.t_submit
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _scatter_rows(state, idx, new_state):
+    """``state`` with ``new_state``'s first ``len(idx)`` rows written at
+    rows ``idx``. Scanned stacks carry a leading group axis, so their batch
+    axis is 1; unscanned ("tail") leaves batch at axis 0. ``state`` is
+    donated: a state the size of the cache is written where it lies, not
+    copied beside the served weights."""
+    n = idx.shape[0]
+
+    def put(path, big, small):
+        axis = 1 if any(getattr(k, "key", None) == "scan"
+                        for k in path) else 0
+        sel = (slice(None),) * axis + (idx,)
+        rows = (slice(None),) * axis + (slice(0, n),)
+        return big.at[sel].set(small[rows].astype(big.dtype))
+
+    return dict(state, layers=jax.tree_util.tree_map_with_path(
+        put, state["layers"], new_state["layers"]))
+
+
 class DecodeEngine:
+    """Serves ``params`` through one jitted decode step and a prefill
+    program per (bucket, prompt length).
+
+    Weight residency: at construction the engine makes the copy it serves,
+    ``self.params`` (``lm.compute_params``). Every leaf the model reads
+    only as ``cfg.dtype`` (attention, FFN, MoE and recurrent projections,
+    ``embed``, ``lm_head``, QKV biases) is cast to ``cfg.dtype`` once, on
+    the device, inside the ``engine.cast_weights`` span (attrs ``leaves``
+    and ``bytes`` written; 0 when nothing needs a cast), so no launch
+    re-casts a weight. Leaves the model reads in float32 (norm gains, the
+    recurrent decay and gate biases) and leaves already in ``cfg.dtype``
+    are the caller's own arrays. The caller's tree is never modified,
+    donated or deleted."""
+
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_len: int = 128):
         self.cfg = cfg
-        self.params = params
+        cast = [x for m, x in zip(
+            jax.tree_util.tree_leaves(lm.compute_casts(params, cfg)),
+            jax.tree_util.tree_leaves(params)) if m]
+        with obs.profiled_span(
+                "engine.cast_weights", leaves=len(cast),
+                bytes=sum(x.size for x in cast)
+                * jnp.dtype(cfg.dtype).itemsize):
+            self.params = jax.block_until_ready(
+                lm.compute_params(params, cfg))
         self.max_batch = max_batch
         self.max_len = max_len
         self.queue: List[Request] = []
@@ -122,23 +165,11 @@ class DecodeEngine:
 
     def _scatter_state(self, slot_idx: List[int], new_state) -> None:
         """Write per-request decode state rows into the batched engine state
-        at ``slot_idx`` (extra bucket-padding rows are dropped). Scanned
-        stacks carry a leading group axis, so their batch axis is 1;
-        unscanned ("tail") leaves batch at axis 0."""
-        idx = jnp.asarray(slot_idx, jnp.int32)
-        n = len(slot_idx)
-
-        def put(path, big, small):
-            axis = 1 if any(getattr(k, "key", None) == "scan"
-                            for k in path) else 0
-            sel = (slice(None),) * axis + (idx,)
-            rows = (slice(None),) * axis + (slice(0, n),)
-            return big.at[sel].set(small[rows].astype(big.dtype))
-
-        layers = jax.tree_util.tree_map_with_path(
-            put, self._state["layers"], new_state["layers"])
-        self._state = dict(self._state)
-        self._state["layers"] = layers
+        at ``slot_idx`` (extra bucket-padding rows are dropped), in place:
+        the engine's state is donated to the write."""
+        self._state = _scatter_rows(self._state,
+                                    jnp.asarray(slot_idx, jnp.int32),
+                                    new_state)
 
     def _seating(self) -> Tuple[List[int], List[Request]]:
         """The free slots and the queue's head that will fill them."""
@@ -173,6 +204,7 @@ class DecodeEngine:
                     new_state = self._prefill_fn(n, L)(self.params,
                                                        jnp.asarray(mat))
                 self._scatter_state([i for i, _ in group], new_state)
+                del new_state      # free it before the next group's prefill
                 for i, r in group:
                     toks[i] = r.prompt[-1]
                     # prompt prefix state covers positions 0..L-2; the
@@ -197,12 +229,12 @@ class DecodeEngine:
                                seated=len(seat)):
             self._fill_slots(seating)
             with obs.profiled_span("engine.dispatch"):
-                # jnp.array copies: the CPU client may alias a host array
-                # that jnp.asarray wraps, and _slot_pos is bumped before
-                # the asynchronously dispatched step has read it
+                # upload a copy: the transfer may read its host array
+                # after this returns (jnp.array does not copy a NumPy
+                # array either), and _slot_pos is bumped right after
                 logits, self._state = self._step_fn(
                     self.params, self._state, self._toks,
-                    jnp.array(self._slot_pos))
+                    jnp.asarray(self._slot_pos.copy()))
             self.last_logits = logits
             self._slot_pos += 1
             with obs.profiled_span("engine.readback"):

@@ -33,11 +33,15 @@ def _inside(child, parent):
     return parent[1] <= child[1] and child[2] <= parent[2]
 
 
+def _tiny(dtype=jnp.float32):
+    return ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
+                       n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=128,
+                       head_dim=16, dtype=dtype, scan_layers=False,
+                       remat=False)
+
+
 def test_a_profiled_run_gives_the_step_span_tree(tmp_path):
-    cfg = ModelConfig(name="tiny", family="dense", n_layers=2, d_model=64,
-                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=128,
-                      head_dim=16, dtype=jnp.float32, scan_layers=False,
-                      remat=False)
+    cfg = _tiny()
     eng = DecodeEngine(cfg, lm.init_lm(jax.random.PRNGKey(0), cfg),
                        max_batch=2, max_len=64)
     # two prompt lengths in the first refill: two prefill groups
@@ -74,3 +78,25 @@ def test_a_profiled_run_gives_the_step_span_tree(tmp_path):
         assert sum(_inside(s, r) for r in refills) == 1
     assert {s[0] for s in spans} == set(PHASES) | {"engine.step",
                                                    "engine.prefill"}
+
+
+def test_the_weight_cast_span_counts_what_it_writes(tmp_path):
+    """One ``engine.cast_weights`` per engine: a bfloat16 config casts the
+    embedding, the head and the 7 matrices of each of its 2 layers, in
+    bytes of bfloat16; weights already in compute dtype write nothing."""
+    bf16 = _tiny(jnp.bfloat16)
+    params = lm.init_lm(jax.random.PRNGKey(0), bf16)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        DecodeEngine(bf16, params, max_batch=2, max_len=16)
+        DecodeEngine(bf16, lm.compute_params(params, bf16), max_batch=2,
+                     max_len=16)
+        DecodeEngine(_tiny(), params, max_batch=2, max_len=16)
+    finally:
+        jax.profiler.stop_trace()
+    casts = sorted((s for s in _engine_spans(tmp_path)
+                    if s[0] == "engine.cast_weights"), key=_start)
+    # embed and lm_head, then per layer wq, wo, wk, wv and the three FFN
+    elems = 2 * 128 * 64 + 2 * (2 * 64 * 64 + 2 * 64 * 32 + 3 * 64 * 128)
+    assert [(s[3]["leaves"], s[3]["bytes"]) for s in casts] == \
+        [(16, 2 * elems), (0, 0), (0, 0)]
